@@ -142,9 +142,13 @@ def _run_cell(cfg: EngineConfig, queue: "multiprocessing.Queue") -> None:
 
 
 def _run_isolated(cfg: EngineConfig) -> Dict[str, float]:
-    """Each cell in a fresh subprocess — no cross-cell thread/CPU
-    interference in the wall-clock numbers (inline fallback when the
-    platform can't fork)."""
+    """Each stub-decode cell in a fresh subprocess — no cross-cell
+    thread/CPU interference in the wall-clock numbers (inline fallback when
+    the platform can't fork).  A cell whose decode runs on the device runs
+    in this process: the chip belongs to one process at a time, and a
+    forked child of a parent that has touched JAX cannot reach it."""
+    if cfg.decode != "stub":
+        return _summarize(cfg)
     try:
         ctx = multiprocessing.get_context("fork")
         queue: "multiprocessing.Queue" = ctx.Queue()

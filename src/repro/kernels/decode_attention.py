@@ -38,7 +38,10 @@ def _kernel(qlen_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     v = v_ref[0, 0].astype(jnp.float32)
     kv_len = qlen_ref[0]
 
+    # fp32 contraction asked for, not left to Mosaic's default: decode is
+    # bound by HBM bytes, not by the MXU passes this adds.
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)  # (g, bk)
     if softcap > 0:
         s = softcap * jnp.tanh(s / softcap)
@@ -52,7 +55,8 @@ def _kernel(qlen_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     p = jnp.exp(s - m_new[:, None])
     l_new = l_prev * alpha + p.sum(axis=-1)
     acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p, v, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     m_scr[...] = m_new
     l_scr[...] = l_new
 
@@ -93,7 +97,9 @@ def flash_decode(q, k, v, kv_len, *, softcap=0.0,
         kernel,
         grid=(B, Hkv, n_kv),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),  # kv_len, tiny
+            # kv_len is read as a scalar in the kernel body: Mosaic loads
+            # scalars only from SMEM (an ANY ref would need an async copy).
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, g, hd), lambda b, h, ki: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_kv, hd), lambda b, h, ki: (b, h, ki, 0)),
             pl.BlockSpec((1, 1, block_kv, hd), lambda b, h, ki: (b, h, ki, 0)),

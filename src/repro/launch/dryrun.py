@@ -41,12 +41,8 @@ from . import steps as S
 
 
 def cost_dict(compiled) -> Dict:
-    """``Compiled.cost_analysis()`` normalized across jax versions: older
-    releases return a one-element list of dicts, newer ones the dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``Compiled.cost_analysis()`` as a dict (empty when XLA reports none)."""
+    return compiled.cost_analysis() or {}
 
 DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,

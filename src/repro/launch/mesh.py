@@ -9,19 +9,11 @@ from __future__ import annotations
 import jax
 
 
-def auto_axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` where supported; jax<0.5 has no AxisType
-    (Auto is already the default there), so pass nothing."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **auto_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
@@ -29,4 +21,4 @@ def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
     n = len(jax.devices())
     assert n % model == 0
     return jax.make_mesh((n // model, model), ("data", "model"),
-                         **auto_axis_types_kwargs(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

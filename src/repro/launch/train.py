@@ -37,12 +37,17 @@ from ..models import config as mc
 from ..models import lm
 from ..optim import AdamWConfig, adamw_init
 from . import steps as S
+from .compile_cache import enable_compile_cache
 
 
 @dataclass
 class RunConfig:
     arch: str = "llama3.2-1b"
     use_smoke: bool = True              # reduced config (CPU-trainable)
+    # Depth cut: layers to build (None = the config's own).  Widths and
+    # vocabulary stay as configured, so a published model can be cut to
+    # what one chip's memory holds.
+    n_layers: Optional[int] = None
     steps: int = 50
     batch: int = 8
     seq_len: int = 128
@@ -79,10 +84,35 @@ def _hosts(n: int) -> List[str]:
     return [f"host{i}" for i in range(n)]
 
 
+def model_config(run: RunConfig) -> mc.ModelConfig:
+    """The model ``train(run)`` builds: the arch's config, reduced when
+    ``use_smoke``, with depth cut to ``n_layers`` when that is set."""
+    cfg = _arch_cfg(run.arch)
+    if run.use_smoke:
+        cfg = mc.smoke(cfg)
+    if run.n_layers is not None:
+        if run.n_layers < 1:
+            raise ValueError(f"n_layers must be >= 1, got {run.n_layers}")
+        cfg = dataclasses.replace(cfg, n_layers=run.n_layers)
+    return cfg
+
+
+def train_settings(run: RunConfig) -> S.TrainSettings:
+    return S.TrainSettings(remat=run.remat,
+                           opt=AdamWConfig(lr=run.lr, weight_decay=0.01),
+                           warmup=run.warmup, stable=10**6, decay=1)
+
+
+def jit_train_step(cfg: mc.ModelConfig, run: RunConfig):
+    """The jitted step ``train(run)`` runs: fwd + bwd + AdamW, with params
+    and optimizer state donated."""
+    return jax.jit(S.make_train_step(cfg, train_settings(run)),
+                   donate_argnums=(0, 1))
+
+
 def train(run: RunConfig) -> RunResult:
     t_start = time.time()
-    cfg = mc.smoke(_arch_cfg(run.arch)) if run.use_smoke \
-        else _arch_cfg(run.arch)
+    cfg = model_config(run)
     if run.data_source.startswith("bytes:"):
         assert cfg.vocab_size >= 256
     dcfg = DataConfig(batch=run.batch, seq_len=run.seq_len,
@@ -90,11 +120,8 @@ def train(run: RunConfig) -> RunResult:
                       seed=run.seed)
     pipeline = make_pipeline(dcfg)
 
-    opt_cfg = AdamWConfig(lr=run.lr, weight_decay=0.01)
-    settings = S.TrainSettings(remat=run.remat, opt=opt_cfg,
-                               warmup=run.warmup, stable=10**6, decay=1)
     params = lm.init_model(cfg, jax.random.key(run.seed))
-    opt_state = adamw_init(params, opt_cfg)
+    opt_state = adamw_init(params, train_settings(run).opt)
 
     store = FileStore(run.ckpt_dir)
     hosts = _hosts(run.n_hosts)
@@ -113,8 +140,7 @@ def train(run: RunConfig) -> RunResult:
             start_step = epoch
             result.restored_from = epoch
 
-    train_step = jax.jit(S.make_train_step(cfg, settings),
-                         donate_argnums=(0, 1))
+    train_step = jit_train_step(cfg, run)
     checkpointers = {h: CornusCheckpointer(store, h, hosts,
                                            straggler_timeout_s=10.0)
                      for h in hosts}
@@ -199,6 +225,7 @@ def main(argv=None):
     ap.add_argument("--data", default="synthetic")
     ap.add_argument("--lr", type=float, default=1e-3)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     run = RunConfig(arch=args.arch, steps=args.steps, batch=args.batch,
                     seq_len=args.seq_len, ckpt_every=args.ckpt_every,
                     ckpt_dir=args.ckpt_dir, n_hosts=args.n_hosts,

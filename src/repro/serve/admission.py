@@ -16,15 +16,23 @@ a serving frontend needs that a storage lane does not:
 
 The decode call itself is pluggable: ``PallasDecode`` drives the
 ``kernels.decode_attention.flash_decode`` TPU kernel over a pooled KV
-cache when jax is importable; ``StubDecode`` is a deterministic latency
-model (one base cost per batch plus a per-item term — the same
-amortization shape as the storage batch lanes) used by the wall-clock
-benches so CI throughput is machine-independent.
+cache (compiled through Mosaic unless the caller asks for the Pallas
+interpreter with ``interpret=True``, as CPU tests do); ``StubDecode`` is a
+deterministic latency model (one base cost per batch plus a per-item
+term — the same amortization shape as the storage batch lanes), reached
+only by asking for ``"stub"``, used by tests and the sleep-model benches.
+
+A decode exception fails its batch's requests (clients see drops) but
+never the serving loop; the batcher counts it in ``decode_errors``, keeps
+the first exception and logs it once, so a kernel that cannot run on the
+device is not mistaken for a slow server.
 """
 from __future__ import annotations
 
+import sys
 import threading
 import time
+import traceback
 from dataclasses import dataclass
 from typing import List, Optional, Sequence
 
@@ -99,7 +107,7 @@ class PallasDecode:
     def __init__(self, slots: int = 64, q_heads: int = 4, kv_heads: int = 2,
                  head_dim: int = 64, max_len: int = 256,
                  block_kv: int = 128, seed: int = 0,
-                 interpret: Optional[bool] = None) -> None:
+                 interpret: bool = False) -> None:
         import jax
         import jax.numpy as jnp
         from ..kernels.decode_attention import flash_decode
@@ -111,8 +119,7 @@ class PallasDecode:
         self.head_dim = head_dim
         self.max_len = max_len
         self.block_kv = block_kv
-        self.interpret = (jax.default_backend() != "tpu"
-                          if interpret is None else interpret)
+        self.interpret = interpret
         self._k = jnp.zeros((slots, kv_heads, max_len, head_dim),
                             jnp.float32)
         self._v = jnp.zeros((slots, kv_heads, max_len, head_dim),
@@ -177,18 +184,14 @@ class PallasDecode:
         return [int(s * 1e4) % 50_000 for s in jax.device_get(scores)]
 
 
-def make_decode(kind: str = "auto", **kwargs):
-    """'stub' | 'pallas' | 'auto' (pallas when jax imports, else stub)."""
+def make_decode(kind: str, **kwargs):
+    """'pallas' (the flash-decode kernel) | 'stub' (the latency model)."""
+    if kind == "pallas":
+        return PallasDecode(**kwargs)
     if kind == "stub":
         return StubDecode(**kwargs)
-    if kind in ("pallas", "auto"):
-        try:
-            return PallasDecode(**kwargs)
-        except ImportError:
-            if kind == "pallas":
-                raise
-            return StubDecode()
-    raise ValueError(f"unknown decode backend {kind!r}")
+    raise ValueError(f"unknown decode backend {kind!r}: "
+                     f"expected 'pallas' or 'stub'")
 
 
 class ContinuousBatcher:
@@ -215,6 +218,8 @@ class ContinuousBatcher:
         self.batches = 0
         self.decoded = 0
         self.max_batch_seen = 0
+        self.decode_errors = 0                  # batches whose decode raised
+        self.first_decode_error: Optional[BaseException] = None
 
     # -- client side --------------------------------------------------------
     def submit(self, req: StepRequest) -> bool:
@@ -299,9 +304,16 @@ class ContinuousBatcher:
             t0 = time.monotonic()
             try:
                 results = self.decode(live)
-            except Exception:
+            except Exception as e:
                 # A decode failure fails the batch's requests, never the
                 # serving loop (clients see a drop and may retry).
+                self.decode_errors += 1
+                if self.first_decode_error is None:
+                    self.first_decode_error = e
+                    print("ContinuousBatcher: decode failed; failing the "
+                          "batch (further errors are counted, not logged):\n"
+                          + "".join(traceback.format_exception(e)),
+                          file=sys.stderr, flush=True)
                 for req in live:
                     req.dropped = True
                     self.dropped += 1

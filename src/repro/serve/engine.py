@@ -50,7 +50,7 @@ __all__ = ["EngineConfig", "ServeEngine", "ServeResult", "run_serve"]
 class EngineConfig:
     session: SessionConfig = field(default_factory=SessionConfig)
     admission: AdmissionConfig = field(default_factory=AdmissionConfig)
-    decode: str = "stub"               # "stub" | "pallas" | "auto"
+    decode: str = "stub"               # "stub" | "pallas"
     decode_kwargs: Dict = field(default_factory=dict)
     clients: int = 8
     steps_per_session: int = 25        # closed loop
@@ -335,6 +335,7 @@ class ServeEngine:
         counters = {
             "submitted": self.batcher.submitted,
             "batches": self.batcher.batches,
+            "decode_errors": self.batcher.decode_errors,
             "max_batch_seen": self.batcher.max_batch_seen,
             "opens": self.mgr.opens,
             "closes": self.mgr.closes,

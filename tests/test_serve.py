@@ -17,8 +17,9 @@ HAS_HYPOTHESIS, given, settings, st = hypothesis_or_stubs()
 
 from repro.core.state import Vote
 from repro.serve import (AdmissionConfig, ContinuousBatcher, EngineConfig,
-                         SessionConfig, SessionManager, StepRequest,
-                         StubDecode, build_session_store, run_serve)
+                         ServeEngine, SessionConfig, SessionManager,
+                         StepRequest, StubDecode, build_session_store,
+                         make_decode, run_serve)
 
 
 # ---------------------------------------------------------------------------
@@ -325,3 +326,77 @@ def test_engine_open_loop_sheds_instead_of_stalling():
     assert rep.committed == r.counters["steps_committed"]
     # Whatever wasn't admitted was shed, not lost: accounting adds up.
     assert rep.completed + rep.dropped <= r.counters["submitted"]
+
+
+# ---------------------------------------------------------------------------
+# Decode backends: the kernel only by name, and failures counted
+# ---------------------------------------------------------------------------
+def test_engine_pallas_decode_in_interpreter_commits_every_step():
+    pytest.importorskip("jax")
+    cfg = EngineConfig(
+        session=SessionConfig(protocol="cornus", backend="memory",
+                              participants_per_txn=2),
+        admission=AdmissionConfig(max_batch=4, window_ms=1.0),
+        decode="pallas",
+        # The CPU has no Mosaic: the interpreter is asked for, never implied.
+        decode_kwargs=dict(slots=8, q_heads=2, kv_heads=1, head_dim=32,
+                           max_len=32, block_kv=16, interpret=True),
+        clients=2, steps_per_session=3)
+    engine = ServeEngine(cfg)
+    assert engine.batcher.decode.interpret is True
+    r = engine.run()
+    assert r.report.committed == 2 * 3
+    assert r.report.dropped == 0
+    assert r.counters["decode_errors"] == 0
+
+
+class _RaisingDecode:
+    def __init__(self) -> None:
+        self.raised: list = []
+
+    def __call__(self, reqs):
+        e = RuntimeError(f"decode refused batch {len(self.raised)}")
+        self.raised.append(e)
+        raise e
+
+
+def test_engine_counts_decode_errors_and_keeps_the_first(capsys):
+    cfg = EngineConfig(
+        session=SessionConfig(protocol="cornus", backend="memory"),
+        admission=AdmissionConfig(max_batch=4, window_ms=1.0),
+        clients=3, steps_per_session=2)
+    engine = ServeEngine(cfg)
+    decode = _RaisingDecode()
+    engine.batcher.decode = decode
+    r = engine.run()
+    # Serving semantics unchanged: the failed batches' steps are drops.
+    assert r.report.committed == 0
+    assert r.report.dropped == 3 * 2
+    assert r.counters["decode_errors"] == len(decode.raised) >= 1
+    assert r.counters["decode_errors"] == r.counters["batches"]
+    assert engine.batcher.first_decode_error is decode.raised[0]
+    # Logged once, however many batches failed.
+    assert capsys.readouterr().err.count("decode failed") == 1
+
+
+def test_serve_bench_runs_device_cells_in_process(monkeypatch):
+    """A cell whose decode needs the chip never forks: a child of a parent
+    that has touched JAX could not reach the device."""
+    import multiprocessing
+
+    from benchmarks import serve_bench
+
+    def no_fork(*a, **k):
+        raise AssertionError("a device cell was forked")
+
+    monkeypatch.setattr(multiprocessing, "get_context", no_fork)
+    monkeypatch.setattr(serve_bench, "_summarize", lambda cfg: {"ran": 1.0})
+    assert serve_bench._run_isolated(EngineConfig(decode="pallas")) == \
+        {"ran": 1.0}
+
+
+def test_make_decode_accepts_only_pallas_and_stub():
+    assert isinstance(make_decode("stub"), StubDecode)
+    for kind in ("auto", "jax", ""):
+        with pytest.raises(ValueError, match="pallas"):
+            make_decode(kind)

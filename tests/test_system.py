@@ -4,6 +4,8 @@
 test_arch_smoke / test_kernels / test_ckpt_commit / test_train_loop; this
 file asserts the cross-layer contracts.)
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -71,12 +73,11 @@ def test_dryrun_lowering_path_smoke():
     from repro.configs import get_config
     from repro.launch import steps as S
     from repro.launch.dryrun import cost_dict, parse_collectives
-    from repro.launch.mesh import auto_axis_types_kwargs
+    from repro.launch.mesh import make_host_mesh
     from repro.launch.sharding import Rules
     from repro.models.config import ShapeConfig, smoke
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"),
-                         **auto_axis_types_kwargs(2))
+    mesh = make_host_mesh()
     rules = Rules(mesh)
     cfg = smoke(get_config("llama3.2-1b"))
     shape = ShapeConfig("tiny_train", seq_len=32, global_batch=2,
@@ -135,3 +136,55 @@ def test_wsd_schedule_shape():
     assert mult[4] == 1.0                   # decay starts at 60
     assert 0.1 <= mult[5] < 1.0
     assert mult[7] == pytest.approx(0.1)    # decayed to final_frac
+
+
+def test_run_config_cuts_depth_and_keeps_widths():
+    from repro.configs import get_config
+    from repro.launch.train import RunConfig, model_config
+    full = get_config("llama3.2-1b")
+    cut = model_config(RunConfig(arch="llama3.2-1b", use_smoke=False,
+                                 n_layers=4))
+    assert cut.n_layers == 4
+    assert cut == dataclasses.replace(full, n_layers=4)
+    assert model_config(RunConfig(use_smoke=False)) == full
+    with pytest.raises(ValueError):
+        model_config(RunConfig(n_layers=0))
+
+
+_CACHE_PROBE = """
+import os
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import enable_compile_cache
+got = enable_compile_cache()
+print(got)
+print(jax.config.jax_compilation_cache_dir)
+if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "default"])
+def test_compile_cache_directory(tmp_path, from_env):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX's own reading of it stands
+    and entries land there; without it the cache is the fixed
+    ``<checkout>/.jax_cache``."""
+    import os
+    import subprocess
+    import sys
+
+    from repro.launch.compile_cache import CHECKOUT
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(CHECKOUT, "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = str(tmp_path / "cache")
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    else:
+        want = os.path.join(CHECKOUT, ".jax_cache")
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == [want, want]
+    if from_env:
+        assert os.listdir(want), "no cache entry written"
