@@ -8,11 +8,11 @@ coordinator can never wedge the fleet, and any host (or a restarting job) can
 resolve an in-flight epoch in bounded time with the termination protocol.
 """
 from .shards import (ec_decode, ec_encode, pack_tree, partition_leaves,
-                     unpack_tree)
+                     to_host, unpack_tree)
 from .commit import CheckpointOutcome, CornusCheckpointer
 from .restore import fetch_payloads, latest_committed, restore_params
 
-__all__ = ["pack_tree", "unpack_tree", "partition_leaves",
+__all__ = ["pack_tree", "unpack_tree", "partition_leaves", "to_host",
            "ec_encode", "ec_decode",
            "CornusCheckpointer", "CheckpointOutcome", "latest_committed",
            "restore_params", "fetch_payloads"]

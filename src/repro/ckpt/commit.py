@@ -27,6 +27,7 @@ import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence
 
+from .. import obs
 from ..core.control import LeaseKeeper, QuorumUnavailable
 from ..core.state import Decision, Vote
 from ..core.storage import FileStore, MemoryStore
@@ -91,10 +92,11 @@ class CornusCheckpointer:
         return lease.holder if lease is not None else self.host
 
     # -- participant side ---------------------------------------------------
-    def _put_payload(self, epoch: int, payload: bytes) -> None:
+    def _put_payload(self, epoch: int, payload: bytes) -> int:
+        """Write this host's payload; the bytes written."""
         if self.ec_k is None:
             self.store.put_data(self.host, _txn(epoch), payload)
-            return
+            return len(payload)
         replicas = self.store.replicas
         alive = self.store.alive_replicas()
         if len(alive) < self.ec_k:
@@ -104,12 +106,16 @@ class CornusCheckpointer:
         frags = ec_encode(payload, self.ec_k, len(replicas))
         for r in alive:
             r.put_data(self.host, _ec_name(epoch), frags[r.index])
+        return sum(len(frags[r.index]) for r in alive)
 
     def vote(self, epoch: int, payload: bytes) -> Vote:
         """Upload this host's shards, then CAS the VOTE-YES."""
-        self._put_payload(epoch, payload)
-        return self.store.log_once(self.host, _txn(epoch), Vote.VOTE_YES,
-                                   writer=self._writer())
+        with obs.span("upload", epoch=epoch) as sp:
+            sp.set(bytes=self._put_payload(epoch, payload))
+        writer = self._writer()
+        with obs.span("log_once", epoch=epoch):
+            return self.store.log_once(self.host, _txn(epoch),
+                                       Vote.VOTE_YES, writer=writer)
 
     # -- collective resolution (termination protocol §3.3) -------------------
     def read_states(self, epoch: int) -> Dict[str, Optional[Vote]]:
@@ -162,19 +168,17 @@ class CornusCheckpointer:
     def save(self, epoch: int, payload: bytes,
              straggler_timeout_s: Optional[float] = None
              ) -> CheckpointOutcome:
-        t0 = time.monotonic()
-        my_vote = self.vote(epoch, payload)
-        t1 = time.monotonic()
+        with obs.span("vote", epoch=epoch, host=self.host) as voted:
+            my_vote = self.vote(epoch, payload)
         if my_vote == Vote.ABORT:
             # A peer already aborted this epoch on our behalf — we were the
             # straggler. Training continues; the epoch is simply not durable.
             return CheckpointOutcome(epoch, Decision.ABORT,
-                                     vote_ms=(t1 - t0) * 1e3)
-        decision, forced = self.resolve(epoch, straggler_timeout_s)
-        t2 = time.monotonic()
-        return CheckpointOutcome(epoch, decision,
-                                 vote_ms=(t1 - t0) * 1e3,
-                                 resolve_ms=(t2 - t1) * 1e3,
+                                     vote_ms=voted.ms)
+        with obs.span("resolve", epoch=epoch) as resolved:
+            decision, forced = self.resolve(epoch, straggler_timeout_s)
+        return CheckpointOutcome(epoch, decision, vote_ms=voted.ms,
+                                 resolve_ms=resolved.ms,
                                  forced_aborts=forced)
 
 

@@ -23,6 +23,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from .. import obs
 from ..core.state import Decision
 from .commit import CornusCheckpointer, _ec_name, _txn
 from .shards import ec_decode, merge_into_tree, unpack_tree
@@ -105,8 +106,16 @@ def fetch_payloads(store, hosts: Sequence[str], epoch: int,
 
 
 def restore_params(store, hosts: Sequence[str], epoch: int, template):
-    """Reassemble the full tree from every host's shard payload."""
-    flat: Dict[str, np.ndarray] = {}
-    for payload in fetch_payloads(store, hosts, epoch).values():
-        flat.update(unpack_tree(payload))
-    return merge_into_tree(template, flat)
+    """Reassemble the full tree from every host's shard payload: ``load``
+    reads and unpacks the payloads, ``put`` copies the leaves to the
+    template's device."""
+    with obs.span("restore", epoch=epoch):
+        with obs.span("load", epoch=epoch) as sp:
+            flat: Dict[str, np.ndarray] = {}
+            nbytes = 0
+            for payload in fetch_payloads(store, hosts, epoch).values():
+                flat.update(unpack_tree(payload))
+                nbytes += len(payload)
+            sp.set(bytes=nbytes)
+        with obs.span("put", epoch=epoch):
+            return merge_into_tree(template, flat)

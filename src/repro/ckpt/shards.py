@@ -1,6 +1,8 @@
 """Shard (de)serialization: pytree leaves ↔ bytes, and host partitioning.
 
 Format: npz of path-keyed arrays (fast, dependency-free, self-describing).
+``to_host`` pulls a tree's device leaves to the host once per save; the
+save then hands that host tree to ``partition_leaves`` and ``pack_tree``.
 ``partition_leaves`` deterministically assigns leaf paths to hosts by a
 size-balanced greedy rule, so a restore can reassemble the full tree from
 any historical host count — this is what makes restarts *elastic*.
@@ -32,6 +34,13 @@ def _flatten(tree) -> Dict[str, np.ndarray]:
                        for p in path)
         flat[key] = np.asarray(leaf)
     return flat
+
+
+def to_host(tree):
+    """The same tree with every leaf copied to the host: ``np.asarray``
+    leaf by leaf, in the tree's order."""
+    import jax
+    return jax.tree_util.tree_map(np.asarray, tree)
 
 
 def pack_tree(tree, keys: Sequence[str] | None = None) -> bytes:

@@ -2,10 +2,15 @@
 
 Runs a real (reduced-config or custom) model on the local device(s):
   data pipeline → jitted train_step (fwd+bwd+AdamW, WSD schedule) →
-  every ``ckpt_every`` steps, a Cornus checkpoint epoch: the process acts as
-  all ``n_hosts`` fleet members (size-balanced shard partitioning), votes
-  each host's shard set into the FileStore, and the epoch commits iff the
+  every ``ckpt_every`` steps, a Cornus checkpoint epoch: the state is pulled
+  to the host once (``ckpt.shards.to_host``), the process acts as all
+  ``n_hosts`` fleet members (size-balanced shard partitioning), votes each
+  host's shard set into the FileStore, and the epoch commits iff the
   collective votes are durable — Algorithm 1, deployed.
+
+Each step and each save is marked with ``repro.obs`` spans: ``step`` ⊃
+``data``, ``h2d``, ``train_step``, ``loss_sync``; ``checkpoint`` ⊃ ``d2h``,
+``partition``, ``pack`` and ``vote`` per host, ``resolve``.
 
 Restart semantics: ``resume=True`` restores the newest COMMITTED epoch
 (in-flight epochs are resolved by the termination protocol, never waited
@@ -27,8 +32,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ckpt import (CornusCheckpointer, latest_committed, pack_tree,
-                    partition_leaves, restore_params)
+from .. import obs
+from ..ckpt import (CheckpointOutcome, CornusCheckpointer, latest_committed,
+                    pack_tree, partition_leaves, restore_params, to_host)
 from ..ckpt.commit import AsyncCheckpointer
 from ..core.state import Decision
 from ..core.storage import FileStore
@@ -150,12 +156,18 @@ def train(run: RunConfig) -> RunResult:
     prefetch = Prefetcher(pipeline, start_step)
     try:
         for step in range(start_step, run.steps):
-            got_step, batch = prefetch.get()
-            assert got_step == step
-            jbatch = {k: jnp.asarray(v) for k, v in batch.items()}
-            params, opt_state, loss = train_step(
-                params, opt_state, jbatch, jnp.asarray(step, jnp.int32))
-            result.losses.append(float(loss))
+            with obs.span("step", step=step):
+                with obs.span("data"):
+                    got_step, batch = prefetch.get()
+                assert got_step == step
+                with obs.span("h2d"):
+                    jbatch = {k: jnp.asarray(v) for k, v in batch.items()}
+                    jstep = jnp.asarray(step, jnp.int32)
+                with obs.span("train_step"):
+                    params, opt_state, loss = train_step(
+                        params, opt_state, jbatch, jstep)
+                with obs.span("loss_sync"):
+                    result.losses.append(float(loss))
             result.steps_done = step + 1
             if run.log_every and (step + 1) % run.log_every == 0:
                 print(f"[train] step {step+1:5d} loss {float(loss):.4f}",
@@ -177,33 +189,43 @@ def train(run: RunConfig) -> RunResult:
 
 def _checkpoint(run, cfg, params, opt_state, epoch, hosts, checkpointers,
                 async_ck):
-    full = {"params": params,
-            "opt": {"m": opt_state["m"], "v": opt_state["v"]}}
-    parts = partition_leaves(full, len(hosts))
-    payloads = {h: pack_tree(full, keys) for h, keys in zip(hosts, parts)}
+    with obs.span("checkpoint", epoch=epoch):
+        with obs.span("d2h", epoch=epoch) as sp:
+            full = to_host({"params": params,
+                            "opt": {"m": opt_state["m"], "v": opt_state["v"]}})
+            sp.set(bytes=sum(leaf.nbytes
+                             for leaf in jax.tree_util.tree_leaves(full)))
+        with obs.span("partition", epoch=epoch):
+            parts = partition_leaves(full, len(hosts))
+        payloads = {}
+        for h, keys in zip(hosts, parts):
+            with obs.span("pack", epoch=epoch, host=h) as sp:
+                payloads[h] = pack_tree(full, keys)
+                sp.set(bytes=len(payloads[h]))
 
-    if run.die_mid_checkpoint_at == epoch:
-        # Crash after host0's vote only: epoch left UNDETERMINED on storage.
-        checkpointers[hosts[0]].vote(epoch, payloads[hosts[0]])
-        raise MidCheckpointCrash(f"injected crash in epoch {epoch}")
+        if run.die_mid_checkpoint_at == epoch:
+            # Crash after host0's vote only: the epoch is left UNDETERMINED
+            # on storage.
+            with obs.span("vote", epoch=epoch, host=hosts[0]):
+                checkpointers[hosts[0]].vote(epoch, payloads[hosts[0]])
+            raise MidCheckpointCrash(f"injected crash in epoch {epoch}")
 
-    if async_ck is not None:
+        if async_ck is not None:
+            for h in hosts:
+                async_ck[h].save(epoch, payloads[h])
+            return None
+        # This process acts as the whole fleet: all hosts vote first (in a
+        # real deployment these are concurrent), then the collective state
+        # resolves.
+        vote_ms = 0.0
         for h in hosts:
-            async_ck[h].save(epoch, payloads[h])
-        return None
-    # This process acts as the whole fleet: all hosts vote first (in a real
-    # deployment these are concurrent), then the collective state resolves.
-    import time as _time
-    t0 = _time.monotonic()
-    for h in hosts:
-        checkpointers[h].vote(epoch, payloads[h])
-    t1 = _time.monotonic()
-    decision, forced = checkpointers[hosts[0]].resolve(epoch)
-    from ..ckpt import CheckpointOutcome
-    return CheckpointOutcome(epoch, decision,
-                             vote_ms=(t1 - t0) * 1e3,
-                             resolve_ms=(_time.monotonic() - t1) * 1e3,
-                             forced_aborts=forced)
+            with obs.span("vote", epoch=epoch, host=h) as voted:
+                checkpointers[h].vote(epoch, payloads[h])
+            vote_ms += voted.ms
+        with obs.span("resolve", epoch=epoch) as resolved:
+            decision, forced = checkpointers[hosts[0]].resolve(epoch)
+        return CheckpointOutcome(epoch, decision, vote_ms=vote_ms,
+                                 resolve_ms=resolved.ms, forced_aborts=forced)
 
 
 def _arch_cfg(arch: str):
