@@ -6,7 +6,7 @@ CAS store (``FileStore``: O_EXCL create-if-absent, or ``MemoryStore`` in
 tests).  Partition names are host ids; the transaction id is the epoch.
 
 Walkthrough of one epoch on host h (Algorithm 1, participant side):
-  1. upload shard payload            → store.put_data(h, "e<N>", bytes)
+  1. upload shard payload            → store.put_data(h, "e<N>", payload)
   2. resp = LogOnce(h, "e<N>", VOTE_YES)
      · resp == ABORT: a peer's termination protocol already gave up on us
        (we were a straggler) — drop the epoch, keep training.
@@ -93,7 +93,8 @@ class CornusCheckpointer:
 
     # -- participant side ---------------------------------------------------
     def _put_payload(self, epoch: int, payload: bytes) -> int:
-        """Write this host's payload; the bytes written."""
+        """Write this host's payload (bytes, or a ``Payload`` in pieces,
+        which the erasure coder joins first); the bytes written."""
         if self.ec_k is None:
             self.store.put_data(self.host, _txn(epoch), payload)
             return len(payload)

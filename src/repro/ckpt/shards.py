@@ -1,6 +1,17 @@
 """Shard (de)serialization: pytree leaves ↔ bytes, and host partitioning.
 
-Format: npz of path-keyed arrays (fast, dependency-free, self-describing).
+Format (``CKS1``): the magic ``b"CKS1"``, the header's length as a
+little-endian uint64, a JSON header listing each leaf in ``keys`` order
+(key, dtype by name, shape, byte offset, byte length, CRC32), then, from
+the next multiple of 64, each leaf's raw C-order bytes at its offset (a
+multiple of 64 past that start), zero-padded between leaves.
+``pack_tree`` returns it as a ``Payload`` held in pieces (the header, then
+a read-only view of each leaf's own memory), so a save copies no
+contiguous leaf: the stores write the pieces in order.  ``unpack_tree``
+takes each leaf as a view of the payload and checks its CRC32; a payload
+in the zip container of ``np.savez`` (checkpoints written before ``CKS1``)
+still goes through ``np.load``.
+
 ``to_host`` pulls a tree's device leaves to the host once per save; the
 save then hands that host tree to ``partition_leaves`` and ``pack_tree``.
 ``partition_leaves`` deterministically assigns leaf paths to hosts by a
@@ -20,8 +31,11 @@ out-of-band metadata.
 from __future__ import annotations
 
 import io
+import json
 import struct
-from typing import Dict, List, Sequence, Tuple
+import zlib
+from concurrent.futures import ThreadPoolExecutor
+from typing import Dict, Iterator, List, Sequence, Tuple
 
 import numpy as np
 
@@ -43,20 +57,113 @@ def to_host(tree):
     return jax.tree_util.tree_map(np.asarray, tree)
 
 
-def pack_tree(tree, keys: Sequence[str] | None = None) -> bytes:
-    """Serialize (a subset of) a pytree's leaves."""
+_MAGIC = b"CKS1"
+_ZIP_MAGIC = b"PK\x03\x04"            # np.savez's container, before CKS1
+_HEADER_LEN = struct.Struct("<Q")
+_ALIGN = 64
+# zlib.crc32 releases the GIL, so leaves are checksummed on this many
+# threads at once.
+_CRC_THREADS = 8
+
+
+class Payload:
+    """A packed payload held in pieces: the header, then each leaf's bytes
+    with the zero padding between them.  ``len()`` is the byte count,
+    ``bytes()`` the joined bytes; iterating gives the pieces in order.
+    ``copied`` counts the leaf bytes that had to be made contiguous."""
+
+    __slots__ = ("pieces", "nbytes", "copied")
+
+    def __init__(self, pieces: List, copied: int):
+        self.pieces = pieces
+        self.nbytes = sum(len(p) for p in pieces)
+        self.copied = copied
+
+    def __len__(self) -> int:
+        return self.nbytes
+
+    def __iter__(self) -> Iterator:
+        return iter(self.pieces)
+
+    def __bytes__(self) -> bytes:
+        return b"".join(self.pieces)
+
+
+def _crcs(buffers: Sequence) -> List[int]:
+    with ThreadPoolExecutor(_CRC_THREADS) as ex:
+        return list(ex.map(zlib.crc32, buffers))
+
+
+def _dtype(name: str) -> np.dtype:
+    try:
+        return np.dtype(name)
+    except TypeError:  # bfloat16 and the other ml_dtypes types
+        import ml_dtypes
+        return np.dtype(getattr(ml_dtypes, name))
+
+
+def _aligned(n: int) -> int:
+    return -(-n // _ALIGN) * _ALIGN
+
+
+def pack_tree(tree, keys: Sequence[str] | None = None) -> Payload:
+    """Serialize (a subset of) a pytree's leaves in the ``CKS1`` format."""
     flat = _flatten(tree)
-    if keys is not None:
-        flat = {k: flat[k] for k in keys}
-    buf = io.BytesIO()
-    np.savez(buf, **flat)
-    return buf.getvalue()
+    if keys is None:
+        keys = list(flat)
+    views, copied = [], 0
+    for k in keys:
+        arr = flat[k]
+        if not (arr.flags.c_contiguous and arr.dtype.isnative):
+            arr = np.ascontiguousarray(
+                arr, dtype=arr.dtype.newbyteorder("="))
+            copied += arr.nbytes
+        view = arr.reshape(-1).view(np.uint8)
+        view.flags.writeable = False
+        views.append(view)
+    entries, offset = [], 0
+    for k, view, crc in zip(keys, views, _crcs(views)):
+        entries.append(dict(key=k, dtype=flat[k].dtype.name,
+                            shape=list(flat[k].shape), offset=offset,
+                            nbytes=len(view), crc32=crc))
+        offset = _aligned(offset + len(view))
+    header = json.dumps({"leaves": entries}).encode()
+    head = _MAGIC + _HEADER_LEN.pack(len(header)) + header
+    pieces: List = [head + bytes(_aligned(len(head)) - len(head))]
+    end = 0
+    for e, view in zip(entries, views):
+        pieces += [bytes(e["offset"] - end), view]
+        end = e["offset"] + e["nbytes"]
+    return Payload(pieces, copied)
 
 
 def unpack_tree(payload: bytes) -> Dict[str, np.ndarray]:
-    buf = io.BytesIO(payload)
-    with np.load(buf) as z:
-        return {k: z[k] for k in z.files}
+    """The path-keyed leaves of a packed payload, as read-only views of it.
+    Raises ``ValueError`` when a leaf's bytes fail their CRC32."""
+    if isinstance(payload, Payload):
+        payload = bytes(payload)
+    magic = payload[:4]
+    if magic == _ZIP_MAGIC:
+        with np.load(io.BytesIO(payload)) as z:
+            return {k: z[k] for k in z.files}
+    if magic != _MAGIC:
+        raise ValueError(f"not a checkpoint payload: magic {magic!r}")
+    start = len(_MAGIC) + _HEADER_LEN.size
+    (n,) = _HEADER_LEN.unpack_from(payload, len(_MAGIC))
+    entries = json.loads(payload[start:start + n])["leaves"]
+    mem = memoryview(payload)[_aligned(start + n):]
+    spans = [mem[e["offset"]:e["offset"] + e["nbytes"]] for e in entries]
+    out = {}
+    for e, span, crc in zip(entries, spans, _crcs(spans)):
+        if len(span) != e["nbytes"] or crc != e["crc32"]:
+            raise ValueError(
+                f"checkpoint leaf {e['key']!r} is corrupt: CRC32 "
+                f"{crc:#010x} over {len(span)} bytes, header says "
+                f"{e['crc32']:#010x} over {e['nbytes']}")
+        dtype = _dtype(e["dtype"])
+        out[e["key"]] = np.frombuffer(
+            span, dtype, e["nbytes"] // dtype.itemsize).reshape(e["shape"])
+    return out
 
 
 def merge_into_tree(tree, flat: Dict[str, np.ndarray]):
@@ -132,6 +239,7 @@ _EC_MAGIC = b"ECS1"
 
 def ec_encode(payload: bytes, k: int, n: int) -> List[bytes]:
     """Encode ``payload`` into ``n`` fragments, any ``k`` of which decode.
+    A ``Payload`` is joined first.
 
     Fragment j is the GF(256) inner product of the k data stripes with the
     Vandermonde row (x_j^0 .. x_j^{k-1}), x_j = j+1: distinct nonzero
@@ -139,6 +247,8 @@ def ec_encode(payload: bytes, k: int, n: int) -> List[bytes]:
     """
     if not 1 <= k <= n <= 255:
         raise ValueError(f"need 1 <= k <= n <= 255, got k={k} n={n}")
+    if isinstance(payload, Payload):
+        payload = bytes(payload)
     data = np.frombuffer(payload, dtype=np.uint8)
     stripe = max(1, -(-len(data) // k))
     padded = np.zeros(k * stripe, dtype=np.uint8)

@@ -1108,10 +1108,17 @@ class FileStore(_ControlledStoreMixin):
         return os.path.join(d, name)
 
     def put_data(self, partition: str, name: str, payload: bytes) -> str:
+        """Write ``payload`` durably: bytes, or an iterable of bytes-like
+        pieces (``ckpt.shards.Payload``) written in order into one file,
+        fsynced once and renamed into place."""
         path = self.data_path(partition, name)
         tmp = path + f".tmp.{os.getpid()}.{threading.get_ident()}"
+        pieces = ((payload,) if isinstance(payload, (bytes, bytearray,
+                                                     memoryview))
+                  else payload)
         with open(tmp, "wb") as f:
-            f.write(payload)
+            for piece in pieces:
+                f.write(piece)
             f.flush()
             os.fsync(f.fileno())
         os.replace(tmp, path)

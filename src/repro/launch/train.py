@@ -10,7 +10,10 @@ Runs a real (reduced-config or custom) model on the local device(s):
 
 Each step and each save is marked with ``repro.obs`` spans: ``step`` ⊃
 ``data``, ``h2d``, ``train_step``, ``loss_sync``; ``checkpoint`` ⊃ ``d2h``,
-``partition``, ``pack`` and ``vote`` per host, ``resolve``.
+``partition``, ``pack`` and ``vote`` per host, ``resolve``.  ``pack``
+lays a host's leaves out in the ``CKS1`` format and checksums each
+(``ckpt.shards``); its ``copied`` attribute counts the leaf bytes that had
+to be made contiguous, 0 when the pulled leaves are written as they are.
 
 Restart semantics: ``resume=True`` restores the newest COMMITTED epoch
 (in-flight epochs are resolved by the termination protocol, never waited
@@ -201,7 +204,7 @@ def _checkpoint(run, cfg, params, opt_state, epoch, hosts, checkpointers,
         for h, keys in zip(hosts, parts):
             with obs.span("pack", epoch=epoch, host=h) as sp:
                 payloads[h] = pack_tree(full, keys)
-                sp.set(bytes=len(payloads[h]))
+                sp.set(bytes=len(payloads[h]), copied=payloads[h].copied)
 
         if run.die_mid_checkpoint_at == epoch:
             # Crash after host0's vote only: the epoch is left UNDETERMINED

@@ -77,6 +77,49 @@ def test_async_checkpoint_commits(tmp_path):
         o.decision == Decision.COMMIT for o in res.ckpt_outcomes)
 
 
+def test_async_checkpoint_restores_saved_state_bit_for_bit(tmp_path,
+                                                           monkeypatch):
+    """The async save's payload references the pulled host arrays, not a
+    copy of them, while training goes on with donated buffers: each stored
+    payload is byte for byte what was packed, and the restore gives back
+    the packed state bit for bit."""
+    import jax
+    import repro.launch.train as T
+    from repro.ckpt import fetch_payloads, restore_params, unpack_tree
+    from repro.ckpt.shards import _flatten
+
+    pack, packed = T.pack_tree, []
+
+    def pack_and_keep(tree, keys=None):
+        payload = pack(tree, keys)
+        packed.append(bytes(payload))
+        return payload
+
+    monkeypatch.setattr(T, "pack_tree", pack_and_keep)
+    run = base_run(tmp_path, async_ckpt=True)
+    res = train(run)
+    hosts = _hosts(run.n_hosts)
+    assert sorted(o.epoch for o in res.ckpt_outcomes) == \
+        [e for e in (8, 16, 24) for _ in hosts]
+    assert all(o.decision == Decision.COMMIT for o in res.ckpt_outcomes)
+    store = FileStore(str(tmp_path))
+    stored = [fetch_payloads(store, hosts, e)[h]
+              for e in (8, 16, 24) for h in hosts]
+    assert stored == packed
+
+    want = {}
+    for payload in packed[-len(hosts):]:
+        want.update(unpack_tree(payload))
+    params = T.lm.init_model(T.model_config(run), jax.random.key(0))
+    got = _flatten(restore_params(
+        store, hosts, 24, {"params": params, "opt": {"m": params,
+                                                     "v": params}}))
+    assert got.keys() == want.keys()
+    for key, leaf in got.items():
+        np.testing.assert_array_equal(leaf.reshape(-1).view(np.uint8),
+                                      want[key].reshape(-1).view(np.uint8))
+
+
 def test_byte_corpus_training(tmp_path):
     """Train on real bytes (this test file) — loss must drop fast on code."""
     src = os.path.abspath(__file__)
