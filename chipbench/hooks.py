@@ -4,10 +4,12 @@
 hook and no deadline.  ``TrainHooks.installed(T)`` swaps, for the length of a
 ``with`` block, a few names that ``train()`` looks up in its module ``T``:
 
-- ``model_config``: the program's model config, with the two fields its
-  MiniCPM entry does not set as the configuration states (``configured``);
+- ``model_config``: the program's model config, with the fields the
+  architecture module sets as the configuration states (its
+  ``configured``);
 - ``make_pipeline`` and ``lm.init_model``: the benchmark's seeded tokens and
-  weights, so that the reference can make the same ones again;
+  the architecture's seeded weights, so that the reference can make the same
+  ones again;
 - ``Prefetcher``: the program's prefetcher, whose ``get`` also opens the
   measured window at the first step after set-up and closes it at the
   deadline (``WindowClosed``); in a mix that saves, it picks instead the
@@ -29,7 +31,6 @@ the block ends.
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import gc
 import time
 from typing import Dict, List, Optional
@@ -38,10 +39,12 @@ import jax
 
 from . import weights as W
 
-# Host spans that a traced run writes into the profiler's trace; the trace
-# reduction names device idle time by the innermost of these.
+# Host spans that a traced run writes into the profiler's trace, the
+# wrappers' and the program's own; the trace reduction names device idle
+# time by the innermost of these.
 SPANS = ("window", "data", "train_step", "checkpoint", "partition", "pack",
-         "vote", "resolve", "restore")
+         "vote", "resolve", "restore", "step", "h2d", "loss_sync", "d2h",
+         "upload", "log_once")
 
 _COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                    "/jax/compilation_cache/cache_retrieval_time_sec")
@@ -55,27 +58,21 @@ class WindowClosed(Exception):
     """Raised from the prefetcher to end ``train()`` at the deadline."""
 
 
-def configured(model_config, dm: dict):
-    """``model_config`` with the norm's eps and the vocabulary set as the
-    configuration states, through the program's own ``ModelConfig`` fields."""
-    def config(run):
-        return dataclasses.replace(model_config(run), norm_eps=dm["eps"],
-                                   vocab_size=dm["vocab"])
-    return config
-
-
 def _full(params, opt_state):
     return {"params": params, "opt": {"m": opt_state["m"],
                                       "v": opt_state["v"]}}
 
 
 class TrainHooks:
-    def __init__(self, *, dm: dict, seed: int, tokens: W.SeededTokens,
+    """``arch`` is the configuration's architecture module, ``dm`` its
+    dimensions."""
+
+    def __init__(self, *, arch, dm: dict, seed: int, tokens: W.SeededTokens,
                  setup_steps: int, ref_steps: int, seconds: float,
                  ckpt_every: int, trace_dir: Optional[str]):
         if ref_steps >= setup_steps:
             raise ValueError("the reference's steps must end in set-up")
-        self.dm, self.seed, self.tokens = dm, seed, tokens
+        self.arch, self.dm, self.seed, self.tokens = arch, dm, seed, tokens
         self.setup_steps, self.ref_steps = setup_steps, ref_steps
         self.seconds, self.ckpt_every = seconds, ckpt_every
         self.trace_dir = trace_dir
@@ -218,9 +215,10 @@ class TrainHooks:
                 return p, o, loss
             hooks.losses[s] = loss
             if s == 0:
-                hooks.grad_norms = W.slice_norms(o["m"])
+                hooks.grad_norms = W.slice_norms(*hooks.arch.slices(o["m"]))
             if s == hooks.ref_steps - 1:
-                hooks.change_norms = W.change_norms(hooks.dm, p, hooks.seed)
+                hooks.change_norms = hooks.arch.change_norms(hooks.dm, p,
+                                                             hooks.seed)
             if s == hooks.setup_steps - 1 and hooks.ckpt_every:
                 # Compile the save's fingerprint in set-up, not in the window.
                 jax.block_until_ready(W.fingerprint(_full(p, o)))
@@ -289,13 +287,13 @@ class TrainHooks:
         """Lay the hooks around the training module ``T`` for a block."""
         from repro.ckpt.commit import CornusCheckpointer
 
-        dm, seed = self.dm, self.seed
+        arch, dm, seed = self.arch, self.dm, self.seed
         jit_step = T.jit_train_step
         swaps = [
-            (T, "model_config", configured(T.model_config, dm)),
+            (T, "model_config", arch.configured(T.model_config, dm)),
             (T, "make_pipeline", lambda _dcfg: self.tokens),
             (T.lm, "init_model",
-             lambda _cfg, _rng, dtype=None: W.program_weights(dm, seed)),
+             lambda _cfg, _rng, dtype=None: arch.program_weights(dm, seed)),
             (T, "Prefetcher", self._prefetcher(T.Prefetcher)),
             (T, "jit_train_step",
              lambda cfg, run: self._step(jit_step(cfg, run))),

@@ -8,7 +8,8 @@ that saves (``ckpt_every``) ends the window at the first save after the
 deadline with ``hooks.WHOLE_SAVES`` whole saves behind it, crashes in it
 through the program's own ``die_mid_checkpoint_at`` once host 0 has voted,
 and then times ``train(resume=True)`` in the same process up to its first
-step's loss, ``resumes`` times.
+step's loss, ``resumes`` times.  Everything that knows the model's shape is
+reached through the configuration's architecture module (``archs``).
 """
 from __future__ import annotations
 
@@ -23,31 +24,22 @@ from typing import Dict, List
 import jax
 import numpy as np
 
+from .. import archs
 from .. import compare as C
 from .. import device
-from .. import flops
 from .. import reference as R
 from .. import weights as W
-from ..hooks import SPANS, TrainHooks, WindowClosed, configured
+from ..hooks import SPANS, TrainHooks, WindowClosed
 
 
 class ConfigMismatch(RuntimeError):
     """The program would not run the configuration the file states."""
 
 
-def _check_program(T, run_cfg, cfg: dict, dm: dict) -> None:
+def _check_program(T, run_cfg, cfg: dict, arch, dm: dict) -> None:
     """Hold the program to the configuration file before anything runs."""
-    mcfg = configured(T.model_config, dm)(run_cfg)
-    want = dict(d_model=dm["d"], n_heads=dm["heads"], n_kv_heads=dm["kv_heads"],
-                hd=dm["hd"], d_ff=dm["ffn"], n_layers=dm["layers"],
-                vocab_size=dm["vocab"], padded_vocab=dm["vocab_rows"],
-                norm_eps=dm["eps"], rope_theta=dm["theta"],
-                embed_scale=dm["scale_emb"], residual_scale=dm["residual"],
-                logit_divisor=dm["logit_div"],
-                tie_embeddings=cfg["tie_word_embeddings"],
-                pattern=("attn",), n_experts=0, qk_norm=False,
-                post_norm=False, attn_softcap=0.0, final_softcap=0.0)
-    for key, value in want.items():
+    mcfg = arch.configured(T.model_config, dm)(run_cfg)
+    for key, value in arch.program_fields(cfg, dm).items():
         got = getattr(mcfg, key)
         same = (math.isclose(got, value, rel_tol=1e-12)
                 if isinstance(value, float) else got == value)
@@ -66,7 +58,7 @@ def _check_program(T, run_cfg, cfg: dict, dm: dict) -> None:
             or st.opt.state_dtype != jax.numpy.float32:
         raise ConfigMismatch("program's optimizer departs from the "
                              "configuration")
-    mine = jax.eval_shape(lambda: W.program_weights(dm, 0))
+    mine = jax.eval_shape(lambda: arch.program_weights(dm, 0))
     theirs = jax.eval_shape(
         lambda: T.lm.init_model(mcfg, jax.random.key(0)))
     if jax.tree_util.tree_structure(mine) != \
@@ -83,15 +75,15 @@ def _key(path) -> str:
                     for p in path)
 
 
-def _state_keys(dm: dict) -> List[str]:
+def _state_keys(arch, dm: dict) -> List[str]:
     """Payload keys of the saved state, in the order of its leaves."""
-    p = jax.eval_shape(lambda: W.program_weights(dm, 0))
+    p = jax.eval_shape(lambda: arch.program_weights(dm, 0))
     tree = {"params": p, "opt": {"m": p, "v": p}}
     return [_key(path) for path, _ in
             jax.tree_util.tree_flatten_with_path(tree)[0]]
 
 
-def _commit_checks(hooks: TrainHooks, dm: dict, ckpt_dir: str,
+def _commit_checks(hooks: TrainHooks, ckpt_dir: str,
                    hosts: List[str], resumed: list,
                    crash_epoch: int) -> Dict[str, dict]:
     from repro.ckpt import fetch_payloads, unpack_tree
@@ -120,7 +112,8 @@ def _commit_checks(hooks: TrainHooks, dm: dict, ckpt_dir: str,
         for payload in fetch_payloads(store, hosts, e).values():
             flat.update(unpack_tree(payload))
         got = [int(np.sum(flat[k].view(np.uint32), dtype=np.uint32))
-               if k in flat else -1 for k in _state_keys(dm)]
+               if k in flat else -1
+               for k in _state_keys(hooks.arch, hooks.dm)]
         mismatched += (len(got) != len(saved)) + sum(
             a != b for a, b in zip(got, saved))
     crashed = reader.global_decision(crash_epoch)
@@ -164,9 +157,11 @@ def reference_numbers(cfg: dict, traffic: dict, seed: int,
                       dtype=jax.numpy.float32):
     """(losses, first clipped gradient norms, change norms) of the
     reference over the first ``reference_steps`` steps."""
-    dm, tokens = R.dims(cfg), tokens_for(cfg, traffic, seed)
+    arch = archs.load(cfg)
+    dm, tokens = arch.dims(cfg), tokens_for(cfg, traffic, seed)
     return R.run_steps(
-        cfg, lambda: W.reference_view(dm, W.program_weights(dm, seed)),
+        cfg, arch,
+        lambda: arch.reference_view(dm, arch.program_weights(dm, seed)),
         [tokens.tokens(i) for i in range(traffic["reference_steps"])], dtype)
 
 
@@ -221,7 +216,8 @@ def run(cfg: dict, traffic: dict, *, seed: int, seconds: float,
 
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    dm = R.dims(cfg)
+    arch = archs.load(cfg)
+    dm = arch.dims(cfg)
     B, S = traffic["batch"], traffic["seq_len"]
     hosts_n = cfg["checkpoint"]["hosts"]
     ckpt_every = traffic["ckpt_every"]
@@ -229,10 +225,10 @@ def run(cfg: dict, traffic: dict, *, seed: int, seconds: float,
     ckpt_dir = tempfile.mkdtemp(prefix="chipbench_ckpt_")
     try:
         run_cfg = run_config(T, cfg, traffic, seed, ckpt_dir)
-        _check_program(T, run_cfg, cfg, dm)
+        _check_program(T, run_cfg, cfg, arch, dm)
         tokens = tokens_for(cfg, traffic, seed)
         hooks = TrainHooks(
-            dm=dm, seed=seed, tokens=tokens,
+            arch=arch, dm=dm, seed=seed, tokens=tokens,
             setup_steps=traffic["setup_steps"],
             ref_steps=traffic["reference_steps"], seconds=seconds,
             ckpt_every=ckpt_every, trace_dir=trace_dir)
@@ -279,7 +275,7 @@ def run(cfg: dict, traffic: dict, *, seed: int, seconds: float,
         program = program_numbers(cfg, traffic, hooks)
         checks = step_checks(cfg, program, reference)
         if crash:
-            checks.update(_commit_checks(hooks, dm, ckpt_dir,
+            checks.update(_commit_checks(hooks, ckpt_dir,
                                          [f"host{i}" for i in range(hosts_n)],
                                          resumed, hooks.crash_epoch))
 
@@ -309,7 +305,7 @@ def run(cfg: dict, traffic: dict, *, seed: int, seconds: float,
         return dict(
             e2e=e2e, checks=checks, device_peak=device_peak,
             attempted=hooks.window_steps + len(saves), failed=failed,
-            ctx=dict(flops_per_step=flops.train_step_flops(dm, B, S),
+            ctx=dict(flops_per_step=arch.train_step_flops(dm, B, S),
                      window_saves=saves, latest=hooks.latest,
                      restores=hooks.restores),
             info=dict(window_s=window_s, own_work_in_window_s=hooks.paused_s,

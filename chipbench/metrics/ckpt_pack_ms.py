@@ -1,6 +1,6 @@
 """The program's own ``pack`` spans of each save that ended without
-raising, summed over its hosts (``np.savez`` of each host's share into
-memory), median over those saves."""
+raising, summed over its hosts (each host's share laid out in the ``CKS1``
+format, one CRC32 a leaf), median over those saves."""
 import statistics
 
 

@@ -37,14 +37,15 @@ def first_steps(cfg: dict, traffic: dict, seed: int):
     """The program's readings over its first ``reference_steps`` steps,
     through ``train()`` under the hooks (no window)."""
     import repro.launch.train as T
-    from chipbench import reference as R
+    from chipbench import archs
     from chipbench.hooks import TrainHooks
     from chipbench.kinds import train as K
 
     steps = traffic["reference_steps"]
     ckpt_dir = tempfile.mkdtemp(prefix="chipbench_ckpt_")
     try:
-        hooks = TrainHooks(dm=R.dims(cfg), seed=seed,
+        arch = archs.load(cfg)
+        hooks = TrainHooks(arch=arch, dm=arch.dims(cfg), seed=seed,
                            tokens=K.tokens_for(cfg, traffic, seed),
                            setup_steps=steps + 1, ref_steps=steps,
                            seconds=math.inf, ckpt_every=0,

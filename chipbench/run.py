@@ -5,10 +5,12 @@
 
 Everything is found by name from ``BENCHMARK.json`` at the checkout's root:
 the cell gives its configuration and traffic mix; the configuration's file
-(``configs/``) gives its ``kind``, whose runner is ``kinds/<kind>.py``; the
-traffic mix is ``traffic/<traffic>.json``; each per-layer metric is read by
-``metrics/<name>.py``, whose ``read(ctx)`` returns a number or ``None``
-(nothing to read: the metric is left out of the line).
+(``configs/``) gives its ``kind``, whose runner is ``kinds/<kind>.py``, and
+its ``arch``, whose module ``archs/<arch>.py`` holds all that knows the
+model's shape; the traffic mix is ``traffic/<traffic>.json``; each
+per-layer metric is read by ``metrics/<name>.py``, whose ``read(ctx)``
+returns a number or ``None`` (nothing to read: the metric is left out of
+the line).
 
 With ``--trace 0`` the line carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, the device's busy and window seconds and
@@ -140,7 +142,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     bench, cell, cfg, traffic = load_cell(args.workload)
-    from chipbench import compare, device
+    from chipbench import archs, compare, device
+    archs.load(cfg)  # a missing module stops the run before the chip
     try:
         devices = device.require_tpu(cell["chips"])
     except device.NoAccelerator as e:

@@ -1,29 +1,23 @@
-"""A cell cut to a size the CPU runs in seconds, for the tests.
-
-The program's own reduced MiniCPM (``RunConfig(use_smoke=True)``): widths
-64/4/2/16/128, vocabulary 512, 2 layers, with the published scalars
-(``scale_emb``, the residual scale of 40 layers, logits divided by 9).
-"""
+"""A cell cut to a size the CPU runs in seconds, for the tests: the
+configuration by its architecture's own ``smoke`` cut, the traffic mix to
+2 x 32 tokens a step and, where it saves, a save every 6 steps."""
 from __future__ import annotations
 
 import copy
 import json
 import os
 
+from chipbench import archs
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
-def config() -> dict:
-    with open(os.path.join(HERE, "configs",
-                           "minicpm-2b-train.vocab-half.json")) as f:
+def config(name: str) -> dict:
+    """The configuration file ``configs/<name>.json``, cut by the
+    architecture it names."""
+    with open(os.path.join(HERE, "configs", name + ".json")) as f:
         cfg = json.load(f)
-    cfg.update(hidden_size=64, intermediate_size=128, num_attention_heads=4,
-               num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
-               vocab_size=512, dim_model_base=64 / 9)
-    cfg["as_run"]["padded_vocab_rows"] = 512
-    cfg["program"] = {"arch": "minicpm-2b", "use_smoke": True,
-                      "n_layers": None}
-    return cfg
+    return archs.load(cfg).smoke(cfg)
 
 
 def traffic(name: str) -> dict:

@@ -38,7 +38,8 @@ def test_fault_makes_the_run_incorrect(cpu_run, fault, cell):
 
 
 def test_control_in_bfloat16_is_not_correct():
-    cfg, traffic = smoke.config(), smoke.traffic("steady")
+    cfg = smoke.config("minicpm-2b-train.vocab-half")
+    traffic = smoke.traffic("steady")
     seed = 2 ** 31 + 11
     ref = K.reference_numbers(cfg, traffic, seed)
     ctl = K.reference_numbers(cfg, traffic, seed, jnp.bfloat16)

@@ -7,24 +7,26 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import compare, reference, smoke, weights
-from chipbench.hooks import configured
+from chipbench import compare, smoke, weights
+from chipbench.archs import minicpm
+
+CONFIG = "minicpm-2b-train.vocab-half"
 
 
 def _program_config(dm):
     from repro.launch.train import RunConfig, model_config
-    return configured(model_config, dm)(
+    return minicpm.configured(model_config, dm)(
         RunConfig(arch="minicpm-2b", use_smoke=True))
 
 
 def test_reference_matches_program_forward_and_grad():
     from repro.models import lm
 
-    cfg = smoke.config()
-    dm = reference.dims(cfg)
+    cfg = smoke.config(CONFIG)
+    dm = minicpm.dims(cfg)
     mcfg = _program_config(dm)
     assert mcfg.norm_eps == cfg["rms_norm_eps"] == 1e-5
-    params = weights.program_weights(dm, 2 ** 40 + 3)
+    params = minicpm.program_weights(dm, 2 ** 40 + 3)
     toks = weights.SeededTokens(dm["vocab"], 2, 32, 7, 10.0).tokens(0)
     batch = {"tokens": jnp.asarray(toks), "labels": jnp.asarray(toks)}
 
@@ -32,8 +34,8 @@ def test_reference_matches_program_forward_and_grad():
         return lm.forward(mcfg, p, batch)[0]
 
     def ref_loss(p):
-        return reference.loss_fn(dm, weights.reference_view(dm, p),
-                                 jnp.asarray(toks))
+        return minicpm.loss_fn(dm, minicpm.reference_view(dm, p),
+                               jnp.asarray(toks))
 
     with jax.default_matmul_precision("highest"):
         lp, gp = jax.value_and_grad(prog_loss)(params)
@@ -51,29 +53,29 @@ def test_padded_rows_enter_the_programs_softmax_and_not_the_references():
     published 122,753-token vocabulary out of the benchmark)."""
     from repro.models import lm
 
-    cfg = smoke.config()
+    cfg = smoke.config(CONFIG)
     cfg["vocab_size"] = 500
-    dm = reference.dims(cfg)
+    dm = minicpm.dims(cfg)
     mcfg = _program_config(dm)
     assert (mcfg.vocab_size, mcfg.padded_vocab) == (500, 512)
-    params = weights.program_weights(dm, 2 ** 33 + 1)
+    params = minicpm.program_weights(dm, 2 ** 33 + 1)
     toks = jnp.asarray(weights.SeededTokens(500, 2, 32, 5, 10.0).tokens(0))
-    w = weights.reference_view(dm, params)
+    w = minicpm.reference_view(dm, params)
     with jax.default_matmul_precision("highest"):
         prog = float(lm.forward(mcfg, params,
                                 {"tokens": toks, "labels": toks})[0])
-        ref = float(reference.loss_fn(dm, w, toks))
-        all_rows = float(reference.loss_fn(dict(dm, vocab=512), w, toks))
+        ref = float(minicpm.loss_fn(dm, w, toks))
+        all_rows = float(minicpm.loss_fn(dict(dm, vocab=512), w, toks))
     assert prog == pytest.approx(all_rows, rel=1e-6)
     assert compare.loss_gap([prog], [ref]) > \
         cfg["correct"]["loss_gap"]["limit"] * 10
 
 
 def test_weights_are_a_function_of_the_whole_seed():
-    dm = reference.dims(smoke.config())
-    a = weights.program_weights(dm, 5)["embed"]
-    b = weights.program_weights(dm, 5 + 2 ** 32)["embed"]
-    c = weights.program_weights(dm, 5)["embed"]
+    dm = minicpm.dims(smoke.config(CONFIG))
+    a = minicpm.program_weights(dm, 5)["embed"]
+    b = minicpm.program_weights(dm, 5 + 2 ** 32)["embed"]
+    c = minicpm.program_weights(dm, 5)["embed"]
     assert not np.array_equal(a, b)
     np.testing.assert_array_equal(a, c)
 

@@ -1,33 +1,18 @@
-"""Weights and tokens made from the seed, and the program's parameter layout.
+"""The seed's key and token stream, and the norms and fingerprints of
+parameter trees, for every architecture.
 
-The benchmark makes both the weights and the token stream, so that the
+The benchmark makes both the weights (each architecture's
+``program_weights``, from ``seed_key``) and the token stream, so that the
 reference can make the same ones again without taking anything from the
-program.  Weights are made on the device in one jitted call, in float32 (the
-type they are trained in), directly in the program's layout: the layers of
-each kind stacked on a leading axis, as ``repro.models.lm`` scans them.
-``reference_view`` names each layer's slice as the reference does.
+program.
 """
 from __future__ import annotations
 
-from functools import partial
-from typing import Dict, List, Tuple
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-# Program leaf (under "layers"/"p0") -> reference name of each layer's slice.
-_LAYER_LEAVES = {
-    ("mixer", "ln"): "attn_norm",
-    ("mixer", "wq"): "wq",
-    ("mixer", "wk"): "wk",
-    ("mixer", "wv"): "wv",
-    ("mixer", "wo"): "wo",
-    ("ffn", "ln"): "mlp_norm",
-    ("ffn", "w_gate"): "w_gate",
-    ("ffn", "w_up"): "w_up",
-    ("ffn", "w_down"): "w_down",
-}
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -38,94 +23,20 @@ def seed_key(seed: int) -> jax.Array:
         jnp.asarray(np.array([seed >> 32, seed & 0xFFFFFFFF], np.uint32)))
 
 
-def leaf_shapes(dm: dict) -> Dict[str, Tuple[Tuple[int, ...], float]]:
-    """Reference name -> (shape of one layer's slice, init stddev; 0 = zeros)."""
-    d, H, KV, hd, F = dm["d"], dm["heads"], dm["kv_heads"], dm["hd"], dm["ffn"]
-    out = {"embed": ((dm["vocab_rows"], d), 0.02), "final_norm": ((d,), 0.0)}
-    per_layer = {
-        "attn_norm": ((d,), 0.0), "wq": ((d, H * hd), d ** -0.5),
-        "wk": ((d, KV * hd), d ** -0.5), "wv": ((d, KV * hd), d ** -0.5),
-        "wo": ((H * hd, d), (H * hd) ** -0.5), "mlp_norm": ((d,), 0.0),
-        "w_gate": ((d, F), d ** -0.5), "w_up": ((d, F), d ** -0.5),
-        "w_down": ((F, d), F ** -0.5)}
-    for l in range(dm["layers"]):
-        for name, spec in per_layer.items():
-            out[f"layers.{l}.{name}"] = spec
-    return out
-
-
-def _make(dm: dict, seed_data: jax.Array):
-    key = jax.random.wrap_key_data(seed_data)
-    made = {}
-    for i, (name, (shape, std)) in enumerate(sorted(leaf_shapes(dm).items())):
-        if std == 0.0:
-            made[name] = jnp.zeros(shape, jnp.float32)
-        else:
-            made[name] = std * jax.random.normal(
-                jax.random.fold_in(key, i), shape, jnp.float32)
-    return made
-
-
-def _to_program(dm: dict, ref: Dict[str, jax.Array]):
-    layers: Dict[str, Dict[str, jax.Array]] = {"mixer": {}, "ffn": {}}
-    for (group, leaf), name in _LAYER_LEAVES.items():
-        layers[group][leaf] = jnp.stack(
-            [ref[f"layers.{l}.{name}"] for l in range(dm["layers"])])
-    return {"embed": ref["embed"], "final_ln": ref["final_norm"],
-            "layers": {"p0": layers}}
-
-
-@partial(jax.jit, static_argnums=0)
-def _program_weights(dm_items: tuple, seed_data: jax.Array):
-    dm = dict(dm_items)
-    return _to_program(dm, _make(dm, seed_data))
-
-
-def program_weights(dm: dict, seed: int):
-    """The program's parameter tree for ``seed``, made in one jitted call."""
-    return _program_weights(tuple(sorted(dm.items())),
-                            jax.random.key_data(seed_key(seed)))
-
-
-def reference_view(dm: dict, tree) -> Dict[str, jax.Array]:
-    """Slice a tree in the program's layout into the reference's names."""
-    out = {"embed": tree["embed"], "final_norm": tree["final_ln"]}
-    for (group, leaf), name in _LAYER_LEAVES.items():
-        stacked = tree["layers"]["p0"][group][leaf]
-        for l in range(dm["layers"]):
-            out[f"layers.{l}.{name}"] = stacked[l]
-    return out
-
-
-def _slice_norms(tree) -> Dict[str, jax.Array]:
-    """Norm of each leaf of a program-layout tree, per layer where stacked,
-    keyed by the reference's names."""
-    out = {"embed": jnp.linalg.norm(tree["embed"].ravel()),
-           "final_norm": jnp.linalg.norm(tree["final_ln"].ravel())}
-    for (group, leaf), name in _LAYER_LEAVES.items():
-        stacked = tree["layers"]["p0"][group][leaf]
-        per = jnp.sqrt(jnp.sum(jnp.square(stacked.reshape(
-            stacked.shape[0], -1)), axis=1))
-        for l in range(stacked.shape[0]):
+def norms_by_slice(whole: Dict[str, jax.Array],
+                   stacked: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """Norm of each whole leaf, and of each layer's slice of each stacked
+    leaf, the slice ``l`` of ``name`` keyed ``layers.<l>.<name>``: the
+    reference's leaves, from an architecture's ``slices``."""
+    out = {k: jnp.linalg.norm(x.ravel()) for k, x in whole.items()}
+    for name, x in stacked.items():
+        per = jnp.sqrt(jnp.sum(jnp.square(x.reshape(x.shape[0], -1)), axis=1))
+        for l in range(x.shape[0]):
             out[f"layers.{l}.{name}"] = per[l]
     return out
 
 
-slice_norms = jax.jit(_slice_norms)
-
-
-@partial(jax.jit, static_argnums=0)
-def _change_norms(dm_items: tuple, tree, seed_data):
-    dm = dict(dm_items)
-    start = _to_program(dm, _make(dm, seed_data))
-    return _slice_norms(jax.tree_util.tree_map(
-        lambda a, b: a - b, tree, start))
-
-
-def change_norms(dm: dict, tree, seed: int) -> Dict[str, jax.Array]:
-    """Norms of ``tree`` minus the weights ``seed`` started from, per slice."""
-    return _change_norms(tuple(sorted(dm.items())), tree,
-                         jax.random.key_data(seed_key(seed)))
+slice_norms = jax.jit(norms_by_slice)
 
 
 @jax.jit
